@@ -32,13 +32,10 @@ def env_info() -> dict:
     cell means something different in interpret mode on one CPU socket
     than compiled on a TPU slice)."""
     dev = jax.devices()[0]
-    try:
-        from repro.kernels.ops import interpret_mode, interpret_mode_source
-        interpret = bool(interpret_mode())
-        interpret_source = interpret_mode_source()
-    except Exception:                                  # pragma: no cover
-        interpret = None
-        interpret_source = None
+    from repro.kernels.ops import interpret_mode, interpret_mode_source
+
+    interpret = interpret_mode()
+    interpret_source = interpret_mode_source()
     return {
         "device_kind": dev.platform,
         "device_model": str(getattr(dev, "device_kind", "") or ""),

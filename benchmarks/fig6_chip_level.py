@@ -47,7 +47,10 @@ _SCRIPT = textwrap.dedent("""
 
 def run() -> list[str]:
     here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ, PYTHONPATH=os.path.join(here, "src"))
+    # a CPU-rig child: pinned to the CPU so it can never contend with
+    # this or any other process for a TPU
+    env = dict(os.environ, PYTHONPATH=os.path.join(here, "src"),
+               JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)
     r = subprocess.run([sys.executable, "-c", _SCRIPT], env=env,
                        capture_output=True, text=True, timeout=560)

@@ -49,7 +49,7 @@ _SCRIPT = textwrap.dedent("""
     import dataclasses, json, time
     import jax, jax.numpy as jnp
     from repro.core import solver, tuning
-    from repro.launch.mesh import make_mesh_compat
+    from repro.launch.mesh import make_mesh
     from repro.models.testing import reduced_config
     from repro.models.transformer import init_params
     from repro.serving.sampler import SamplerConfig
@@ -61,8 +61,8 @@ _SCRIPT = textwrap.dedent("""
     HZ = 4                        # fused cells' steps per dispatch
 
     x = jax.random.normal(jax.random.PRNGKey(0), (B, V), jnp.float32)
-    mesh_v = make_mesh_compat((1, D), ("data", "model"))
-    mesh_s = make_mesh_compat((D, 1), ("data", "model"))
+    mesh_v = make_mesh((1, D), ("data", "model"))
+    mesh_s = make_mesh((D, 1), ("data", "model"))
 
     cfg = dataclasses.replace(
         reduced_config("internlm2-1.8b"), n_layers=2, d_model=32,
@@ -180,7 +180,10 @@ _SCRIPT = textwrap.dedent("""
 def run() -> list[str]:
     global _PAYLOAD
     here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ, PYTHONPATH=os.path.join(here, "src"))
+    # a CPU-rig child: pinned to the CPU so it can never contend with
+    # this or any other process for a TPU
+    env = dict(os.environ, PYTHONPATH=os.path.join(here, "src"),
+               JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)
     # tuned cells micro-benchmark + persist winners; keep that out of the
     # user's real cache (one throwaway cache shared across device counts)

@@ -44,8 +44,8 @@ _SCRIPT = textwrap.dedent("""
 
     mesh = None
     if D > 1:
-        from repro.launch.mesh import make_mesh_compat
-        mesh = make_mesh_compat((2, D // 2), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, D // 2), ("data", "model"))
 
     # Shape chosen with care, both knobs matter:
     #   * streams long enough (n_new=80) that greedy decode settles into
@@ -104,7 +104,10 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ, PYTHONPATH=os.path.join(here, "src"))
+    # a CPU-rig child: pinned to the CPU so it can never contend with
+    # this or any other process for a TPU
+    env = dict(os.environ, PYTHONPATH=os.path.join(here, "src"),
+               JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)
 
     r = subprocess.run(

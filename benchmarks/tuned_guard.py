@@ -35,12 +35,12 @@ _SCRIPT = textwrap.dedent("""
     import json, time
     import jax, jax.numpy as jnp
     from repro.core import solver, tuning
-    from repro.launch.mesh import make_mesh_compat
+    from repro.launch.mesh import make_mesh
 
     B, V, K = 8, 8192, 50
     ROUNDS, SPEC_K = 6, 4
     x = jax.random.normal(jax.random.PRNGKey(0), (B, V), jnp.float32)
-    mesh = make_mesh_compat((1, D), ("data", "model"))
+    mesh = make_mesh((1, D), ("data", "model"))
 
     def timed(fn, reps=7):
         jax.block_until_ready(fn())
@@ -95,7 +95,10 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ, PYTHONPATH=os.path.join(here, "src"))
+    # a CPU-rig child: pinned to the CPU so it can never contend with
+    # this or any other process for a TPU
+    env = dict(os.environ, PYTHONPATH=os.path.join(here, "src"),
+               JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)
     env.setdefault("REPRO_TUNING_CACHE",
                    os.path.join(os.getcwd(), "tuning_cache.json"))

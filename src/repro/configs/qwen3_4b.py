@@ -1,4 +1,4 @@
-"""qwen3-4b — dense GQA with qk-norm, head_dim 128 [hf:Qwen/Qwen3-8B; hf]."""
+"""qwen3-4b — dense GQA with qk-norm, head_dim 128 [hf:Qwen/Qwen3-4B; hf]."""
 from repro.models.config import ModelConfig
 
 CONFIG = ModelConfig(
@@ -15,5 +15,6 @@ CONFIG = ModelConfig(
     act="swiglu",
     norm="rmsnorm",
     rope_theta=1_000_000.0,
-    source="hf:Qwen/Qwen3-8B; hf",
+    tie_embeddings=True,   # tie_word_embeddings in the published config
+    source="hf:Qwen/Qwen3-4B; hf",
 )

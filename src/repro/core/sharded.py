@@ -39,7 +39,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.core.bisect import _sign_bit
-from repro.core.solver import _solve_rounds, shard_map_compat
+from repro.core.solver import _solve_rounds
 
 
 def _pad_fill(interior: jax.Array, n_fill: int) -> jax.Array:
@@ -95,8 +95,9 @@ def _cached_sharded_solve(
         )
         return lm[0]
 
-    shmapped = shard_map_compat(
-        per_device, mesh, in_specs=(P(), P(), P()), out_specs=P()
+    shmapped = jax.shard_map(
+        per_device, mesh=mesh, in_specs=(P(), P(), P()), out_specs=P(),
+        check_vma=False,
     )
     return jax.jit(shmapped)
 

@@ -48,6 +48,7 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import importlib
+import logging
 import threading
 from typing import Callable
 
@@ -57,20 +58,10 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core.bisect import _sign_bit
 
+log = logging.getLogger("repro.solver")
+
 Array = jax.Array
 MultiEval = Callable[[Array], Array]          # taus (B, M) -> f values (B, M)
-
-
-def shard_map_compat(fn, mesh, in_specs, out_specs):
-    """jax.shard_map across jax versions (top-level only in newer jax;
-    the experimental location spells check_vma as check_rep)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=False)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -514,11 +505,8 @@ def _measure_candidates(key, candidates, policy, statics) -> list[dict]:
     # compiled candidate call would fail.  eval_context (not
     # ensure_compile_time_eval, whose eager-constant-folding flag leaks
     # into the nested jit trace) makes the measurement truly eager.
-    try:
-        from jax._src.core import eval_context
-    except ImportError:                                # pragma: no cover
-        import contextlib
-        eval_context = contextlib.nullcontext
+    from jax._src.core import eval_context
+
     with eval_context():
         return _measure_candidates_eager(key, candidates, policy, statics,
                                          time, np, tuning)
@@ -566,7 +554,10 @@ def _measure_candidates_eager(key, candidates, policy, statics, time, np,
                             "collectives": coll})
         except Exception:
             # infeasible candidate (e.g. forced placement the mesh
-            # cannot honour) — reported as NaN, never selected
+            # cannot honour) — reported as NaN, never selected; the
+            # tuner raises if no candidate survives
+            log.warning("solver candidate %s failed for %s", decision, key,
+                        exc_info=True)
             reports.append({"seconds": float("nan"), "collectives": None})
     return reports
 
@@ -697,9 +688,8 @@ def _build_sharded_solve(mesh, kind, backend, rounds, spec_k, iterations,
     in_specs = ((P(data_spec, va),)
                 + tuple(P(data_spec) if nd else P() for nd in arr_ndims))
     out_specs = (P(data_spec), P(data_spec))
-    return jax.jit(
-        shard_map_compat(per_device, mesh, in_specs, out_specs)
-    )
+    return jax.jit(jax.shard_map(per_device, mesh=mesh, in_specs=in_specs,
+                                 out_specs=out_specs, check_vma=False))
 
 
 def kinds() -> list[str]:

@@ -174,12 +174,20 @@ class ConfigKey:
 
 def device_platform() -> tuple[str, str]:
     """(platform, device model string) of device 0 — the key's
-    ``device_kind`` and the profile selector."""
-    try:
-        dev = jax.devices()[0]
-        return dev.platform, str(getattr(dev, "device_kind", "") or "")
-    except Exception:                                  # pragma: no cover
-        return "cpu", ""
+    ``device_kind`` and the profile selector.
+
+    Raises for a platform no profile describes, and for a TPU whose kind
+    is not the chip the "tpu" profile's constants were taken from: an
+    unknown device gets no default.
+    """
+    dev = jax.devices()[0]
+    platform, kind = dev.platform, dev.device_kind
+    profile_for(platform)
+    if platform == "tpu" and kind not in TPU_KINDS:
+        raise RuntimeError(
+            f"no hardware profile for TPU kind {kind!r}; the 'tpu' "
+            f"profile describes {TPU_KINDS}")
+    return platform, kind
 
 
 # ---------------------------------------------------------------------------
@@ -216,19 +224,18 @@ class HardwareProfile:
     vmem_bytes: int = 16 * 1024 * 1024
 
 
+# The device kinds the "tpu" profile describes (jax's ``device_kind`` of
+# a TPU v5e chip); any other TPU raises in device_platform().
+TPU_KINDS = ("TPU v5 lite",)
+
 PROFILES: dict[str, HardwareProfile] = {
-    # roofline.py: 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link ICI
+    # TPU v5e (Google Cloud "TPU v5e" page): 197 TFLOP/s bf16, 819 GB/s
+    # HBM, 1,600 Gbit/s ICI; VMEM is Mosaic's default 16 MiB scoped limit
     "tpu": HardwareProfile(
         flops=197e12, mem_bw=819e9, join_alpha=2e-6, link_bw=50e9,
         dispatch=4e-6, broadcast_spill=0.05,
         backend_overhead={"jnp": 0.0, "pallas": 0.0},
         vmem_bytes=16 * 1024 * 1024,
-    ),
-    "gpu": HardwareProfile(
-        flops=60e12, mem_bw=1500e9, join_alpha=8e-6, link_bw=25e9,
-        dispatch=8e-6, broadcast_spill=0.1,
-        backend_overhead={"jnp": 0.0, "pallas": 0.0},
-        vmem_bytes=8 * 1024 * 1024,
     ),
     # host-platform "devices" are threads of one socket: collectives are
     # runtime rendezvous + memcpy (BENCH_scaling.json join deltas of
@@ -253,7 +260,13 @@ _KIND_FLOPS = {
 
 
 def profile_for(platform: str) -> HardwareProfile:
-    return PROFILES.get(platform, PROFILES["cpu"])
+    """The profile for ``platform``; raises for one it does not know."""
+    try:
+        return PROFILES[platform]
+    except KeyError:
+        raise RuntimeError(
+            f"no hardware profile for platform {platform!r}; known: "
+            f"{sorted(PROFILES)}") from None
 
 
 def predict_cost(
@@ -909,38 +922,35 @@ class Tuner:
                 if extra.placement in options and extra.backend in backends \
                         and extra not in cand:
                     cand.append(extra)
-            try:
-                reports = list(measure(cand))
-            except Exception:
-                reports = []
-            if reports and len(reports) == len(cand):
-                pairs = [(r["seconds"], d, r)
-                         for r, d in zip(reports, cand)
-                         if r["seconds"] == r["seconds"]
-                         and r["seconds"] > 0]     # drop NaN/failed
-                if pairs:
-                    _, d_best, _ = min(pairs, key=lambda p: p[0])
-                    d_best = dataclasses.replace(d_best, source="measured")
-                    label = (lambda d: f"{d.placement}/{d.backend}"
-                             f"/k{d.spec_k}")
-                    entry = {
-                        "decision": d_best.to_json(),
-                        "measured_us": {
-                            label(d): round(r["seconds"] * 1e6, 1)
-                            for r, d in zip(reports, cand)
-                        },
-                        # REAL join term per sharded candidate, priced
-                        # from compiled HLO (analyse_hlo collective_detail)
-                        "join_hlo": {
-                            label(d): r["collectives"]
-                            for r, d in zip(reports, cand)
-                            if r.get("collectives")
-                        },
-                    }
-                    with self._lock:
-                        self._entries[key.cache_key()] = entry
-                        self._save_locked()
-                    return d_best
+            reports = list(measure(cand))
+            pairs = [(r["seconds"], d) for r, d in zip(reports, cand)
+                     if r["seconds"] == r["seconds"]
+                     and r["seconds"] > 0]     # drop NaN/failed
+            if len(reports) != len(cand) or not pairs:
+                raise RuntimeError(
+                    f"every measured candidate failed for {key}")
+            _, d_best = min(pairs, key=lambda p: p[0])
+            d_best = dataclasses.replace(d_best, source="measured")
+            label = (lambda d: f"{d.placement}/{d.backend}"
+                     f"/k{d.spec_k}")
+            entry = {
+                "decision": d_best.to_json(),
+                "measured_us": {
+                    label(d): round(r["seconds"] * 1e6, 1)
+                    for r, d in zip(reports, cand)
+                },
+                # REAL join term per sharded candidate, priced
+                # from compiled HLO (analyse_hlo collective_detail)
+                "join_hlo": {
+                    label(d): r["collectives"]
+                    for r, d in zip(reports, cand)
+                    if r.get("collectives")
+                },
+            }
+            with self._lock:
+                self._entries[key.cache_key()] = entry
+                self._save_locked()
+            return d_best
         return dataclasses.replace(best, source="model")
 
     # -- the kernel-geometry decision procedure (DESIGN.md §15) -------------
@@ -960,7 +970,8 @@ class Tuner:
         ``{"block_v": 2048}``) — returned verbatim when tuning is
         disabled, always in the measured candidate set.  measure:
         callback timing candidate param dicts (seconds each, NaN for a
-        failed candidate), supplied by ``kernels/ops.py``.  Mirrors
+        failed candidate; the tier raises when every one failed),
+        supplied by ``kernels/ops.py``.  Mirrors
         :meth:`decide`: disabled -> fixed, cache hit -> legality-checked
         replay, analytic -> cheapest roofline candidate, measured
         (``tune``/:func:`autotune`/``REPRO_AUTOTUNE``) -> timed top-3 +
@@ -1002,27 +1013,25 @@ class Tuner:
             fx = KernelDecision.make(fixed, source="fixed")
             if all(c.block != fx.block for c in cand):
                 cand.append(fx)
-            try:
-                times = list(measure([c.params for c in cand]))
-            except Exception:
-                times = []
-            if times and len(times) == len(cand):
-                pairs = [(t, c) for t, c in zip(times, cand)
-                         if t == t and t > 0]        # drop NaN/failed
-                if pairs:
-                    _, d_best = min(pairs, key=lambda p: p[0])
-                    d_best = dataclasses.replace(d_best, source="measured")
-                    entry = {
-                        "decision": d_best.to_json(),
-                        "measured_us": {
-                            c.label(): round(t * 1e6, 1)
-                            for t, c in zip(times, cand) if t == t
-                        },
-                    }
-                    with self._lock:
-                        self._kernels[ck] = entry
-                        self._save_locked()
-                    return _remember(d_best)
+            times = list(measure([c.params for c in cand]))
+            pairs = [(t, c) for t, c in zip(times, cand)
+                     if t == t and t > 0]        # drop NaN/failed
+            if len(times) != len(cand) or not pairs:
+                raise RuntimeError(
+                    f"every measured geometry failed for {key}")
+            _, d_best = min(pairs, key=lambda p: p[0])
+            d_best = dataclasses.replace(d_best, source="measured")
+            entry = {
+                "decision": d_best.to_json(),
+                "measured_us": {
+                    c.label(): round(t * 1e6, 1)
+                    for t, c in zip(times, cand) if t == t
+                },
+            }
+            with self._lock:
+                self._kernels[ck] = entry
+                self._save_locked()
+            return _remember(d_best)
         return _remember(dataclasses.replace(best, source="model"))
 
 

@@ -16,8 +16,9 @@ from __future__ import annotations
 
 LANE = 128          # TPU lane width: last-dim tiles are multiples of this
 DEFAULT_BLOCK_V = 2048   # legacy vocab tile (f32: 8 KiB — deep in VMEM)
-VMEM_BYTES = 16 * 1024 * 1024   # per-core VMEM (v4-class); fit checks
-# budget a fraction of this so double-buffered pipelining has headroom
+VMEM_BYTES = 16 * 1024 * 1024   # Mosaic's default scoped-VMEM limit per
+# kernel on TPU v5e (of 128 MiB physical; the "tpu" profile's vmem_bytes);
+# fit checks budget a fraction of it so double-buffering has headroom
 
 
 def pad_to(n: int, mult: int) -> int:

@@ -95,10 +95,10 @@ def _flash_fwd_pallas(q, k, v, *, q_chunk, kv_chunk, window, interpret):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def flash_fwd(q, k, v, q_chunk=512, kv_chunk=1024, window=0,
-              interpret=True):
+def flash_fwd(q, k, v, q_chunk, kv_chunk, window, interpret):
     """Causal flash attention; q/k/v: (B, S, H, D) with equal head counts
-    (callers repeat/pad GQA heads first).  S must divide by the chunks."""
+    (callers repeat/pad GQA heads first).  S must divide by the chunks.
+    ``interpret`` comes from ``kernels.ops.interpret_mode()``."""
     return _flash_fwd_pallas(q, k, v, q_chunk=q_chunk, kv_chunk=kv_chunk,
                              window=window, interpret=interpret)
 

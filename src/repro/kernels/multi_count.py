@@ -8,6 +8,10 @@ answers ALL candidates in a single tiled sweep, so the speculative width
 
 Layout (TPU target):
   * grid = (B, V // BLOCK_V): one batch row per grid row, vocab tiled.
+  * operands are viewed as (B, 1, ·) with the batch dim squeezed out of
+    every block: Mosaic requires a block's last two dims to be (8, 128)
+    multiples or the whole array dims, and a (1, BLOCK_V) block over a
+    (B, V) array is neither once B > 1.
   * logits block (1, BLOCK_V) streamed HBM -> VMEM per grid step.
   * taus block (1, M_pad) resident per row (M_pad = lane-padded candidates —
     the paper's false-sharing 2-D padding becomes lane alignment here).
@@ -70,11 +74,11 @@ def multi_count(logits: jax.Array, taus: jax.Array, *,
         _kernel,
         grid=(B, n_steps),
         in_specs=[
-            pl.BlockSpec((1, block), lambda b, v: (b, v)),
-            pl.BlockSpec((1, m_pad), lambda b, v: (b, 0)),
+            pl.BlockSpec((None, 1, block), lambda b, v: (b, 0, v)),
+            pl.BlockSpec((None, 1, m_pad), lambda b, v: (b, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, m_pad), lambda b, v: (b, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, m_pad), jnp.float32),
+        out_specs=pl.BlockSpec((None, 1, m_pad), lambda b, v: (b, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, 1, m_pad), jnp.float32),
         interpret=interpret,
-    )(logits_p, taus_p)
-    return out[:, :M]
+    )(logits_p[:, None, :], taus_p[:, None, :])
+    return out[:, 0, :M]

@@ -11,7 +11,8 @@ expectation), so the kernel accumulates the pair
   s[m] = sum_v exp(z_v / T_m)            (normaliser)
   w[m] = sum_v (z_v / T_m) exp(z_v / T_m)
 
-across vocab tiles into a revisited (1, 2, M_pad) output block; the wrapper
+across vocab tiles into a revisited (2, M_pad) output block (batch dim
+squeezed out of every block, as in ``multi_count``); the wrapper
 finalises ``H = log(s) - w / s``.  The row max is subtracted up front (in
 the wrapper), which makes every exp argument <= 0 — no overflow, no online
 max-rescaling needed, and H is shift-invariant so the result is exact.
@@ -50,9 +51,8 @@ def _kernel(z_ref, ts_ref, out_ref):
     e = jnp.exp(zt)
     s = jnp.sum(e, axis=-1)                       # (1, M_pad)
     w = jnp.sum(zt * e, axis=-1)                  # (1, M_pad)
-    out_ref[...] += jnp.concatenate(
-        [s[:, None, :], w[:, None, :]], axis=1
-    ).astype(out_ref.dtype)
+    out_ref[0:1, :] += s.astype(out_ref.dtype)
+    out_ref[1:2, :] += w.astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("block_v", "interpret"))
@@ -85,13 +85,13 @@ def multi_entropy_moments(
         _kernel,
         grid=(B, n_steps),
         in_specs=[
-            pl.BlockSpec((1, block), lambda b, v: (b, v)),
-            pl.BlockSpec((1, m_pad), lambda b, v: (b, 0)),
+            pl.BlockSpec((None, 1, block), lambda b, v: (b, 0, v)),
+            pl.BlockSpec((None, 1, m_pad), lambda b, v: (b, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 2, m_pad), lambda b, v: (b, 0, 0)),
+        out_specs=pl.BlockSpec((None, 2, m_pad), lambda b, v: (b, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, 2, m_pad), jnp.float32),
         interpret=interpret,
-    )(z_p, ts_p)
+    )(z_p[:, None, :], ts_p[:, None, :])
     return acc[:, 0, :M], acc[:, 1, :M]
 
 

@@ -5,9 +5,10 @@ The nucleus (top-p) solve's "function evaluation" is
 vocab.  Runahead bisection asks for that mass at 2**k - 1 candidate
 thresholds per round; this kernel answers ALL candidates for ALL batch rows
 in a single tiled sweep, the mass-analogue of ``multi_count`` (same layout:
-grid = (B, V // BLOCK_V), logits tile streamed HBM -> VMEM, lane-padded
-candidate row resident, output block revisited/accumulated over vocab
-tiles).
+grid = (B, V // BLOCK_V), operands viewed as (B, 1, ·) with the batch
+dim squeezed out of each block, logits tile streamed HBM -> VMEM,
+lane-padded candidate row resident, output block revisited/accumulated
+over vocab tiles).
 
 Padding: probs are padded with -1.0 (a probability can never be negative,
 so padded lanes are below every candidate threshold and contribute zero
@@ -66,11 +67,11 @@ def multi_mass(probs: jax.Array, taus: jax.Array, *,
         _kernel,
         grid=(B, n_steps),
         in_specs=[
-            pl.BlockSpec((1, block), lambda b, v: (b, v)),
-            pl.BlockSpec((1, m_pad), lambda b, v: (b, 0)),
+            pl.BlockSpec((None, 1, block), lambda b, v: (b, 0, v)),
+            pl.BlockSpec((None, 1, m_pad), lambda b, v: (b, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, m_pad), lambda b, v: (b, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, m_pad), jnp.float32),
+        out_specs=pl.BlockSpec((None, 1, m_pad), lambda b, v: (b, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, 1, m_pad), jnp.float32),
         interpret=interpret,
-    )(probs_p, taus_p)
-    return out[:, :M]
+    )(probs_p[:, None, :], taus_p[:, None, :])
+    return out[:, 0, :M]

@@ -1,12 +1,11 @@
 """jit'd public wrappers for the Pallas kernels.
 
-On a TPU backend the kernels compile natively; elsewhere (this CPU
-container) they run in interpret mode, which executes the kernel body in
-Python for correctness validation — the BlockSpec tiling is identical.
-The resolved mode is computed ONCE (it keyed a backend probe per call
-before PR 10) and can be forced either way with
-``REPRO_PALLAS_INTERPRET=0|1`` — e.g. ``=1`` to smoke-test the interpret
-path on a TPU host, ``=0`` to trust a non-TPU Mosaic backend.
+On a TPU backend the kernels always compile natively.  Elsewhere (the CPU
+test rig) they run in interpret mode, which executes the kernel body as
+jax ops for correctness validation — the BlockSpec tiling is identical.
+The resolved mode is computed ONCE.  ``REPRO_PALLAS_INTERPRET=0|1`` is
+honoured off-TPU only (``=0`` to trust a non-TPU Mosaic backend); on a
+TPU, asking for the interpreter raises rather than hide the chip.
 
 Every launch is routed through the tuner's kernel tier (DESIGN.md §15):
 a :class:`~repro.core.tuning.KernelKey` built from the call's static
@@ -19,6 +18,7 @@ signatures — tuning is invisible here just as it is for the solver.
 from __future__ import annotations
 
 import functools
+import logging
 import os
 
 import jax
@@ -33,6 +33,8 @@ from repro.kernels import runahead_threshold as _rt
 from repro.kernels import taylor_eval as _te
 from repro.kernels import blocks
 
+log = logging.getLogger("repro.kernels")
+
 INTERPRET_ENV = "REPRO_PALLAS_INTERPRET"
 _TRUE = ("1", "true", "yes", "on")
 _FALSE = ("0", "false", "no", "off")
@@ -43,19 +45,22 @@ _INTERPRET: bool | None = None      # resolved once, see interpret_mode()
 def interpret_mode() -> bool:
     """The resolved Pallas interpret mode, computed once per process.
 
-    ``REPRO_PALLAS_INTERPRET=0|1`` overrides; otherwise interpret
-    everywhere except a real TPU backend.  :func:`reset_interpret_mode`
-    drops the memo (tests that flip the env var mid-process).
+    False on a TPU backend, where ``REPRO_PALLAS_INTERPRET=1`` raises.
+    Off-TPU, True unless ``REPRO_PALLAS_INTERPRET=0``.
+    :func:`reset_interpret_mode` drops the memo (tests that flip the env
+    var mid-process).
     """
     global _INTERPRET
     if _INTERPRET is None:
         env = os.environ.get(INTERPRET_ENV, "").strip().lower()
-        if env in _FALSE:
+        if jax.default_backend() == "tpu":
+            if env in _TRUE:
+                raise RuntimeError(
+                    f"{INTERPRET_ENV}={env!r} asks for the Pallas "
+                    "interpreter on a TPU backend; kernels compile there")
             _INTERPRET = False
-        elif env in _TRUE:
-            _INTERPRET = True
         else:
-            _INTERPRET = jax.default_backend() != "tpu"
+            _INTERPRET = env not in _FALSE
     return _INTERPRET
 
 
@@ -116,11 +121,8 @@ def _measure_kernel(kernel, key, candidates):
     # the triggering launch is itself being traced (see
     # solver._measure_candidates for why eval_context, not
     # ensure_compile_time_eval).
-    try:
-        from jax._src.core import eval_context
-    except ImportError:                                # pragma: no cover
-        import contextlib
-        eval_context = contextlib.nullcontext
+    from jax._src.core import eval_context
+
     with eval_context():
         return _measure_kernel_eager(kernel, key, candidates, time, np)
 
@@ -191,7 +193,7 @@ def _measure_kernel_eager(kernel, key, candidates, time, np):
                 _pa.paged_attend, context=context, **p, interpret=interp)
 
     else:
-        return [float("nan")] * len(candidates)
+        raise ValueError(f"no measurement harness for kernel {kernel!r}")
 
     times = []
     for params in candidates:
@@ -206,6 +208,10 @@ def _measure_kernel_eager(kernel, key, candidates, time, np):
             reps.sort()
             times.append(reps[len(reps) // 2])
         except Exception:
+            # reported as NaN, never selected; the tuner raises if no
+            # candidate survives
+            log.warning("%s geometry %s failed for %s", kernel, params, key,
+                        exc_info=True)
             times.append(float("nan"))
     return times
 

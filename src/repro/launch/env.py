@@ -10,10 +10,14 @@ stacks (olmax / HomebrewNLP style):
   * ``JAX_DEFAULT_DTYPE_BITS=32`` / ``JAX_ENABLE_X64=0`` — pin the
     default dtype story so a stray python float never upcasts a model
     to f64 on CPU;
-  * ``XLA_FLAGS`` — ``--xla_step_marker_location`` (step-granular
-    profiling on TPU) and ``--xla_force_host_platform_device_count``
-    (the multi-device CPU test rig), merged WITHOUT clobbering flags the
-    operator already exported.
+  * ``XLA_FLAGS`` — ``--xla_force_host_platform_device_count`` (the
+    multi-device CPU test rig), merged WITHOUT clobbering flags the
+    operator already exported.  (No TPU-only flag goes here: jaxlib
+    parses ``XLA_FLAGS`` against its own flag list on every platform and
+    aborts on one it does not know, e.g. ``--xla_step_marker_location``.
+    TPU compiler flags belong in ``LIBTPU_INIT_ARGS``.);
+  * the persistent compile cache — ``$JAX_COMPILATION_CACHE_DIR`` when
+    set, else one fixed directory in the checkout.
 
 Two launch-time facts cannot be fixed from inside the process and are
 handled by ``launch/run.sh`` instead:
@@ -38,21 +42,20 @@ TCMALLOC_THRESHOLD = "60000000000"
 
 _MERGE_FLAGS = "XLA_FLAGS"
 
+COMPILE_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+# The persistent compile cache's home when the environment names none:
+# one fixed directory inside the checkout (git-ignored).  The path is
+# part of what a cache entry is found by, so it never depends on a temp
+# name, a pid or the time.
+DEFAULT_COMPILE_CACHE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))), ".jax_cache")
 
-def tpu_present() -> bool:
-    """Whether a TPU runtime is plausibly attached, WITHOUT touching jax
-    (XLA_FLAGS must be final before backend init).  TPU-only flags like
-    ``--xla_step_marker_location`` make a CPU-only XLA build hard-abort
-    at flag parse, so they are gated on this."""
-    if os.environ.get("TPU_NAME") or os.environ.get("TPU_WORKER_ID"):
-        return True
-    try:
-        import glob
-        # device nodes only — a pip-installed libtpu wheel proves nothing
-        # about the machine (this container ships one with no TPU)
-        return bool(glob.glob("/dev/accel*") or glob.glob("/dev/vfio/*"))
-    except Exception:                                  # pragma: no cover
-        return False
+
+def compile_cache_dir() -> str:
+    """``$JAX_COMPILATION_CACHE_DIR`` when set, else the fixed
+    in-checkout :data:`DEFAULT_COMPILE_CACHE`."""
+    return os.environ.get(COMPILE_CACHE_ENV) or DEFAULT_COMPILE_CACHE
 
 
 def _merge_xla_flags(flags: list[str]) -> str:
@@ -77,7 +80,8 @@ def configure(
     """Apply launch hygiene to ``os.environ``; returns what was resolved.
 
     Must run before jax initialises its backend (XLA_FLAGS and the dtype
-    pins are read at first touch).  Everything uses setdefault semantics:
+    pins are read at first touch).  Also points jax's persistent
+    compile cache at :func:`compile_cache_dir`.  Everything uses setdefault semantics:
     an operator's explicit export always wins.  ``host_devices`` forces N
     CPU host devices (the multi-device test rig — serve.py's old inline
     flag append, now merged properly so repeated calls don't stack
@@ -90,14 +94,19 @@ def configure(
     os.environ.setdefault("JAX_DEFAULT_DTYPE_BITS", str(int(dtype_bits)))
     os.environ.setdefault("JAX_ENABLE_X64", "0")
 
-    # step markers give the TPU profiler per-step boundaries; the flag
-    # only EXISTS in TPU builds (CPU XLA aborts on unknown flags)
-    flags = ["--xla_step_marker_location=1"] if tpu_present() else []
+    flags = []
     if host_devices:
         flags.append(
             f"--xla_force_host_platform_device_count={int(host_devices)}")
     flags.extend(extra_xla_flags)
     merged = _merge_xla_flags(flags)
+
+    # jax reads $JAX_COMPILATION_CACHE_DIR itself when it is set; this
+    # sets the same directory, or the fixed default, and never another
+    import jax
+
+    cache_dir = compile_cache_dir()
+    jax.config.update("jax_compilation_cache_dir", cache_dir)
 
     if "libtcmalloc" not in os.environ.get("LD_PRELOAD", ""):
         # can't be retrofitted here — the linker read LD_PRELOAD at exec.
@@ -112,18 +121,17 @@ def configure(
         "jax_default_dtype_bits": os.environ.get("JAX_DEFAULT_DTYPE_BITS"),
         "jax_enable_x64": os.environ.get("JAX_ENABLE_X64"),
         "ld_preload": os.environ.get("LD_PRELOAD", ""),
+        "compile_cache_dir": cache_dir,
     }
 
 
 def describe() -> dict:
     """The resolved launch environment, for logs and bench artifacts —
     includes the pallas interpret mode actually in effect."""
-    try:
-        from repro.kernels.ops import interpret_mode, interpret_mode_source
-        interp: bool | None = interpret_mode()
-        interp_src: str | None = interpret_mode_source()
-    except Exception:                                  # pragma: no cover
-        interp = interp_src = None
+    from repro.kernels.ops import interpret_mode, interpret_mode_source
+
+    interp = interpret_mode()
+    interp_src = interpret_mode_source()
     return {
         "xla_flags": os.environ.get("XLA_FLAGS", ""),
         "ld_preload": os.environ.get("LD_PRELOAD", ""),

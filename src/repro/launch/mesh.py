@@ -6,15 +6,12 @@ from __future__ import annotations
 import jax
 
 
-def make_mesh_compat(shape, axes) -> jax.sharding.Mesh:
-    """jax.make_mesh across jax versions: `axis_types` (and AxisType) only
-    exist in newer jax; older versions default to Auto semantics anyway."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
+def make_mesh(shape, axes) -> jax.sharding.Mesh:
+    """jax.make_mesh with every axis Auto (jax defaults to Explicit): the
+    serving and solver code place arrays with shardings and shard_map,
+    not with explicit-axis types."""
     return jax.make_mesh(
-        shape, axes, axis_types=(axis_type.Auto,) * len(axes)
-    )
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def parse_mesh(spec: str) -> jax.sharding.Mesh:
@@ -30,9 +27,9 @@ def parse_mesh(spec: str) -> jax.sharding.Mesh:
     except ValueError:
         raise ValueError(f"bad mesh spec {spec!r}; want e.g. '2x4'") from None
     if len(dims) == 2:
-        return make_mesh_compat(dims, ("data", "model"))
+        return make_mesh(dims, ("data", "model"))
     if len(dims) == 3:
-        return make_mesh_compat(dims, ("pod", "data", "model"))
+        return make_mesh(dims, ("pod", "data", "model"))
     raise ValueError(f"mesh spec {spec!r} must have 2 or 3 dims")
 
 
@@ -41,7 +38,7 @@ def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     Multi-pod: 2x16x16 = 512 chips (pod, data, model)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def data_axes(mesh: jax.sharding.Mesh):

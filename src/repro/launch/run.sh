@@ -32,17 +32,6 @@ export TF_CPP_MIN_LOG_LEVEL="${TF_CPP_MIN_LOG_LEVEL:-2}"
 export JAX_DEFAULT_DTYPE_BITS="${JAX_DEFAULT_DTYPE_BITS:-32}"
 export JAX_ENABLE_X64="${JAX_ENABLE_X64:-0}"
 
-# -- XLA: step markers give the profiler per-step boundaries on TPU.
-#    TPU-ONLY: the flag does not exist in CPU/GPU XLA builds, which
-#    hard-abort on unknown flags — gate on visible TPU evidence.
-#    Append-only — never clobber operator flags.
-if [[ "${XLA_FLAGS:-}" != *"--xla_step_marker_location"* ]]; then
-  if [[ -n "${TPU_NAME:-}" || -n "${TPU_WORKER_ID:-}" ]] \
-     || compgen -G "/dev/accel*" > /dev/null; then
-    export XLA_FLAGS="${XLA_FLAGS:-} --xla_step_marker_location=1"
-  fi
-fi
-
 entry="${REPRO_ENTRY:-}"
 if [[ -z "$entry" ]]; then
   case "${1:-serve}" in

@@ -644,6 +644,10 @@ class ContinuousScheduler:
         self.keys = jnp.zeros((n_slots, 2), jnp.uint32)
         self._policy = None
         if mesh is not None:
+            # each device runs the whole model on its own slots, so the
+            # params are replicated over the mesh once, here — left
+            # uncommitted they would sit on one device
+            self.params = jax.device_put(params, NamedSharding(mesh, P()))
             self._policy, slot_axes = slot_policy(mesh, n_slots)
             self.token, self.pos, self.keys, dense_cache = (
                 _shard_slot_state(mesh, slot_axes, self.token, self.pos,

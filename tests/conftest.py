@@ -24,7 +24,7 @@ def _bounded_jit_cache():
 
     The suite has grown past the point where one pytest process can hold
     every module's jit cache at once: with ~450 tests' executables live,
-    XLA CPU (jaxlib 0.4.37) segfaults deterministically inside a later
+    XLA CPU segfaulted deterministically inside a later
     compile — dropping any module from the run (or running the crashing
     module alone) makes it pass, so the crash is accumulated native
     state, not any one test's graph.  Per-module clearing caps the live

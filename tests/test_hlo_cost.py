@@ -106,18 +106,18 @@ SHARD_MAP_SCRIPT = textwrap.dedent("""
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    from repro.core.solver import shard_map_compat
     from repro.core import tuning
     from repro.launch.hlo_cost import analyse_hlo
-    from repro.launch.mesh import make_mesh_compat
+    from repro.launch.mesh import make_mesh
 
-    mesh = make_mesh_compat((2,), ("model",))
+    mesh = make_mesh((2,), ("model",))
 
     def f(x):
         return jax.lax.psum(jnp.sum(x, axis=-1), "model")
 
-    fn = jax.jit(shard_map_compat(
-        f, mesh, in_specs=(P(None, "model"),), out_specs=P(None)))
+    fn = jax.jit(jax.shard_map(
+        f, mesh=mesh, in_specs=(P(None, "model"),), out_specs=P(None),
+        check_vma=False))
     hlo = fn.lower(jnp.ones((4, 64), jnp.float32)).compile().as_text()
 
     r = analyse_hlo(hlo)

@@ -212,6 +212,21 @@ def test_interpret_env_override(monkeypatch, _restore_interpret):
     assert ops.interpret_mode_source() == "env"
 
 
+def test_interpret_refused_on_tpu(monkeypatch, _restore_interpret):
+    """On a TPU backend the kernels always compile: the env override
+    asking for the interpreter raises instead of hiding the chip."""
+    import jax
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.delenv(ops.INTERPRET_ENV, raising=False)
+    ops.reset_interpret_mode()
+    assert ops.interpret_mode() is False
+    monkeypatch.setenv(ops.INTERPRET_ENV, "1")
+    ops.reset_interpret_mode()
+    with pytest.raises(RuntimeError, match="TPU"):
+        ops.interpret_mode()
+
+
 def test_interpret_autodetect_and_memo(monkeypatch, _restore_interpret):
     monkeypatch.delenv(ops.INTERPRET_ENV, raising=False)
     ops.reset_interpret_mode()

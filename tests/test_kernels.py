@@ -85,7 +85,7 @@ def test_multi_entropy_extreme_logit_range():
 
 
 @pytest.mark.parametrize("V,k", [(1000, 5), (5000, 50), (18992, 64)])
-@pytest.mark.parametrize("spec_k", [3, 5])
+@pytest.mark.parametrize("spec_k", [3, 5, 8])
 def test_fused_runahead_matches_unfused(V, k, spec_k):
     rng = np.random.default_rng(V + k)
     logits = jnp.asarray(rng.normal(size=(3, V)).astype(np.float32))

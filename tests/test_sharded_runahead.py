@@ -17,8 +17,8 @@ SCRIPT = textwrap.dedent("""
     import jax.numpy as jnp
     from repro.core import find_root_runahead_sharded, find_root_serial, make_paper_f
 
-    from repro.launch.mesh import make_mesh_compat
-    mesh = make_mesh_compat((2, 4), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2, 4), ("data", "model"))
     f = make_paper_f(50)
     a, b = jnp.float32(1.0), jnp.float32(2.0)
     for k in (2, 3, 4):
@@ -39,8 +39,8 @@ PARAM_SPEC_SCRIPT = textwrap.dedent("""
     from repro.launch.specs import params_specs
     from repro.configs.registry import get_config
 
-    from repro.launch.mesh import make_mesh_compat
-    mesh = make_mesh_compat((2, 4), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2, 4), ("data", "model"))
     cfg = get_config("qwen2-moe-a2.7b")
     params = params_specs(cfg)
     sh = make_param_shardings(mesh, params)
@@ -69,8 +69,8 @@ UNEVEN_SCRIPT = textwrap.dedent("""
     from repro.core import find_root_serial, make_paper_f
     from repro.core import sharded
 
-    from repro.launch.mesh import make_mesh_compat
-    mesh = make_mesh_compat((8,), ("model",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((8,), ("model",))
     f = make_paper_f(50)
     a, b = jnp.float32(1.0), jnp.float32(2.0)
 
@@ -106,8 +106,8 @@ RETRACE_SCRIPT = textwrap.dedent("""
     from repro.core import make_paper_f
     from repro.core import sharded
 
-    from repro.launch.mesh import make_mesh_compat
-    mesh = make_mesh_compat((2, 4), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2, 4), ("data", "model"))
     f = make_paper_f(50)
     a, b = jnp.float32(1.0), jnp.float32(2.0)
 

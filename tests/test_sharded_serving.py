@@ -29,9 +29,9 @@ KINDS_SCRIPT = textwrap.dedent("""
     import jax
     import jax.numpy as jnp
     from repro.core import solver, tuning
-    from repro.launch.mesh import make_mesh_compat
+    from repro.launch.mesh import make_mesh
 
-    mesh = make_mesh_compat((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     key = jax.random.PRNGKey(0)
     x = jax.random.normal(key, (8, 256), jnp.float32) * 3.0
     probs = jax.nn.softmax(x, axis=-1)
@@ -70,7 +70,7 @@ KINDS_SCRIPT = textwrap.dedent("""
             print(f"{backend}/{kind} bit-exact (fixed + tuned)")
         # pure data parallelism (model axis size 1): the fused
         # whole-solve top-k hook stays on the per-device full rows
-        mesh_dp = make_mesh_compat((8, 1), ("data", "model"))
+        mesh_dp = make_mesh((8, 1), ("data", "model"))
         with tuning.disabled():
             ref = solver.solve_kind("count_above", x, backend=backend,
                                     rounds=6, spec_k=4, k=17)
@@ -89,7 +89,7 @@ SERVING_SCRIPT = textwrap.dedent("""
     import dataclasses
     import jax
     import jax.numpy as jnp
-    from repro.launch.mesh import make_mesh_compat
+    from repro.launch.mesh import make_mesh
     from repro.models.testing import reduced_config
     from repro.models.transformer import init_params
     from repro.serving.sampler import SamplerConfig
@@ -101,7 +101,7 @@ SERVING_SCRIPT = textwrap.dedent("""
         n_heads=2, n_kv_heads=2, d_head=16, d_ff=64, vocab=128,
     )
     params = init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
-    mesh = make_mesh_compat((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
 
     def workload():
         sc = lambda **kw: SamplerConfig(backend=backend, **kw)
@@ -140,7 +140,7 @@ SPEC_SCRIPT = textwrap.dedent("""
     import dataclasses
     import jax
     import jax.numpy as jnp
-    from repro.launch.mesh import make_mesh_compat
+    from repro.launch.mesh import make_mesh
     from repro.models.testing import reduced_config
     from repro.models.transformer import init_params
     from repro.serving.sampler import SamplerConfig
@@ -153,7 +153,7 @@ SPEC_SCRIPT = textwrap.dedent("""
         n_heads=2, n_kv_heads=2, d_head=16, d_ff=64, vocab=128,
     )
     params = init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
-    mesh = make_mesh_compat((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
 
     # repetitive greedy workload: drafts actually get accepted, so the
     # verify/rollback/position-jump path runs under GSPMD for real
@@ -183,7 +183,7 @@ PAGED_SCRIPT = textwrap.dedent("""
     import dataclasses
     import jax
     import jax.numpy as jnp
-    from repro.launch.mesh import make_mesh_compat
+    from repro.launch.mesh import make_mesh
     from repro.models.testing import reduced_config
     from repro.models.transformer import init_params
     from repro.serving.sampler import SamplerConfig
@@ -195,7 +195,7 @@ PAGED_SCRIPT = textwrap.dedent("""
         n_heads=2, n_kv_heads=2, d_head=16, d_ff=64, vocab=128,
     )
     params = init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
-    mesh = make_mesh_compat((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
 
     pre = list(range(1, 10))       # shared prefix: COW forks under GSPMD
     def workload():
@@ -259,7 +259,7 @@ FUSED_SCRIPT = textwrap.dedent("""
     import dataclasses
     import jax
     import jax.numpy as jnp
-    from repro.launch.mesh import make_mesh_compat
+    from repro.launch.mesh import make_mesh
     from repro.models.testing import reduced_config
     from repro.models.transformer import init_params
     from repro.serving.draft import RepeatLastDrafter
@@ -272,7 +272,7 @@ FUSED_SCRIPT = textwrap.dedent("""
         n_heads=2, n_kv_heads=2, d_head=16, d_ff=64, vocab=128,
     )
     params = init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
-    mesh = make_mesh_compat((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
 
     def workload():
         sc = lambda **kw: SamplerConfig(backend=backend, **kw)

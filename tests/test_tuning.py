@@ -541,15 +541,54 @@ def test_kernel_corrupted_entry_not_replayed(tmp_path, bad_block):
 
 def test_kernel_measured_failures_fall_back(tmp_path):
     """All-NaN measurements (every candidate crashed) must not persist a
-    winner — the analytic choice stands and the cache stays empty."""
+    winner, and must not fall back in silence either: the tier raises
+    and the cache stays empty."""
     import os
 
     path = str(tmp_path / "cache.json")
     t = tuning.Tuner(path)
-    with tuning.autotune():
-        d = t.decide_kernel(_kkey(), fixed=_KFIXED,
-                            measure=lambda c: [float("nan")] * len(c))
-    assert d.source == "model"
+    with tuning.autotune(), pytest.raises(RuntimeError, match="failed"):
+        t.decide_kernel(_kkey(), fixed=_KFIXED,
+                        measure=lambda c: [float("nan")] * len(c))
     if os.path.exists(path):
         assert _kkey().cache_key() not in \
             json.load(open(path)).get("kernels", {})
+
+
+def test_solver_measured_failures_raise(tmp_path):
+    """The solver tier's dual: no surviving candidate is an error."""
+    t = tuning.Tuner(str(tmp_path / "cache.json"))
+    with tuning.autotune(), pytest.raises(RuntimeError, match="failed"):
+        t.decide(_key(), options=OPTIONS, backends=("jnp",),
+                 fixed=tuning.Decision(spec_k=4, rounds=6,
+                                       placement="single", backend="jnp"),
+                 measure=lambda c: [{"seconds": float("nan"),
+                                     "collectives": None}] * len(c))
+
+
+class _FakeDevice:
+    def __init__(self, platform, device_kind):
+        self.platform, self.device_kind = platform, device_kind
+
+
+@pytest.mark.parametrize("platform,kind,ok", [
+    ("tpu", "TPU v5 lite", True),
+    ("tpu", "TPU v4", False),        # a TPU the "tpu" profile is not from
+    ("gpu", "NVIDIA H100", False),   # a platform with no profile at all
+    ("cpu", "cpu", True),
+])
+def test_device_platform_refuses_unknown_devices(monkeypatch, platform,
+                                                 kind, ok):
+    monkeypatch.setattr(jax, "devices",
+                        lambda *a: [_FakeDevice(platform, kind)])
+    if ok:
+        assert tuning.device_platform() == (platform, kind)
+    else:
+        with pytest.raises(RuntimeError, match="no hardware profile"):
+            tuning.device_platform()
+
+
+def test_profile_for_unknown_platform_raises():
+    assert tuning.profile_for("tpu") is tuning.PROFILES["tpu"]
+    with pytest.raises(RuntimeError, match="no hardware profile"):
+        tuning.profile_for("gpu")
