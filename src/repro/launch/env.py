@@ -12,10 +12,10 @@ stacks (olmax / HomebrewNLP style):
     to f64 on CPU;
   * ``XLA_FLAGS`` — ``--xla_force_host_platform_device_count`` (the
     multi-device CPU test rig), merged WITHOUT clobbering flags the
-    operator already exported.  (No TPU-only flag goes here: jaxlib
+    operator already exported.  No TPU-only flag goes here: jaxlib
     parses ``XLA_FLAGS`` against its own flag list on every platform and
-    aborts on one it does not know, e.g. ``--xla_step_marker_location``.
-    TPU compiler flags belong in ``LIBTPU_INIT_ARGS``.);
+    aborts on one it does not know (``--xla_step_marker_location``);
+    TPU compiler flags belong in ``LIBTPU_INIT_ARGS``;
   * the persistent compile cache — ``$JAX_COMPILATION_CACHE_DIR`` when
     set, else one fixed directory in the checkout.
 
@@ -80,12 +80,12 @@ def configure(
     """Apply launch hygiene to ``os.environ``; returns what was resolved.
 
     Must run before jax initialises its backend (XLA_FLAGS and the dtype
-    pins are read at first touch).  Also points jax's persistent
-    compile cache at :func:`compile_cache_dir`.  Everything uses setdefault semantics:
-    an operator's explicit export always wins.  ``host_devices`` forces N
-    CPU host devices (the multi-device test rig — serve.py's old inline
-    flag append, now merged properly so repeated calls don't stack
-    duplicates).
+    pins are read at first touch), and points jax's persistent compile
+    cache at :func:`compile_cache_dir`.  Everything uses setdefault
+    semantics: an operator's explicit export always wins.
+    ``host_devices`` forces N CPU host devices (the multi-device test
+    rig — serve.py's old inline flag append, now merged properly so
+    repeated calls don't stack duplicates).
     """
     if quiet:
         os.environ.setdefault("TF_CPP_MIN_LOG_LEVEL", "2")
