@@ -24,6 +24,13 @@ Host state is a plain slot table (request id, tokens emitted, remaining
 budget) plus a FIFO of waiting requests.  Admission runs the ordinary B=1
 prefill and scatters the resulting cache into the free slot; eviction is
 just marking the slot free — the next admission overwrites it.
+
+``admit`` and ``step`` mark their phases with ``jax.profiler`` host spans
+(``serve.admit`` and ``serve.admit.prefill|sample|book``; ``serve.step``
+and ``serve.step.prepare|launch|readback|commit``).  A profiler trace
+puts them on the device's clock, so each stretch of device idle time
+lies inside the host phase that left the device waiting.  With no trace
+running a span costs about a microsecond.
 """
 from __future__ import annotations
 
@@ -34,6 +41,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import solver
@@ -676,7 +684,11 @@ class ContinuousScheduler:
         self._finished: list[FinishedRequest] = []
         self._step_args = None     # (slots_arr, active, enable, k, greedy)
         self.n_decode_steps = 0          # batched decode iterations (stats)
-        self.n_dispatches = 0            # jitted calls issued (stats)
+        # compiled step and admission programs launched (stats); the
+        # eager array updates around them (slot writes, step inputs) are
+        # device launches too and are not counted here: a device trace
+        # counts them all (launches_per_step, bench/spans.py)
+        self.n_dispatches = 0
         self.n_host_syncs = 0            # device->host reads (stats)
         self.n_drafted = 0               # drafted tokens offered to verify
         self.n_accepted = 0              # drafted tokens accepted
@@ -752,6 +764,13 @@ class ContinuousScheduler:
         request at B=1: prefill, split the request key, sample the first
         token from the prefill logits with the request's own config.
         """
+        with TraceAnnotation("serve.admit", rid=rid,
+                             length=np.size(prompt)):
+            return self._admit(rid, prompt, n_new, seed, sampler,
+                               encoder_frames, eos_id)
+
+    def _admit(self, rid, prompt, n_new, seed, sampler, encoder_frames,
+               eos_id) -> bool:
         prompt = jnp.asarray(prompt, jnp.int32).reshape(1, -1)
         self.validate_request(n_new, sampler, prompt_len=prompt.shape[1])
         free = [i for i, s in enumerate(self.slots) if s is None]
@@ -787,61 +806,68 @@ class ContinuousScheduler:
                     self.alloc.release(chain)
                     return False
                 chain.append(pid)
-            logits, self.pool, key, sub = _admit_paged(
-                self.params, prompt, self.pool,
-                jnp.asarray(chain, jnp.int32), jax.random.PRNGKey(seed),
-                cfg=self.cfg, context=self.context,
-                page_size=self.page_size, skip=skip,
-            )
+            with TraceAnnotation("serve.admit.prefill"):
+                logits, self.pool, key, sub = _admit_paged(
+                    self.params, prompt, self.pool,
+                    jnp.asarray(chain, jnp.int32), jax.random.PRNGKey(seed),
+                    cfg=self.cfg, context=self.context,
+                    page_size=self.page_size, skip=skip,
+                )
             if not plan.wrap:
                 for j in range(plan.register_cap):
                     self.alloc.register_prefix(
                         prefix_key(ptoks, (j + 1) * self.page_size),
                         chain[j])
         elif encoder_frames is None:
-            logits, self.cache, key, sub = _admit_slot(
-                self.params, prompt, self.cache, jnp.int32(i),
-                jax.random.PRNGKey(seed), cfg=self.cfg,
-                context=self.context, cache_dtype=self.cache_dtype,
-            )
+            with TraceAnnotation("serve.admit.prefill"):
+                logits, self.cache, key, sub = _admit_slot(
+                    self.params, prompt, self.cache, jnp.int32(i),
+                    jax.random.PRNGKey(seed), cfg=self.cfg,
+                    context=self.context, cache_dtype=self.cache_dtype,
+                )
         else:                        # enc-dec: frames vary per request,
             # keep this rare path eager rather than grow the jit cache
-            logits, self.cache = prefill_into_slot(
-                self.cfg, self.params, prompt, self.context, self.cache, i,
-                encoder_frames=encoder_frames, kv_dtype=self.cache_dtype,
-            )
-            key, sub = jax.random.split(jax.random.PRNGKey(seed))
-        first = int(_admit_sample(
-            logits, sub[None], SlotSamplers.stack([sampler]),
-            spec_k=self.spec_k, rounds=self.rounds, backend=self.backend,
-            enable=_enable_bits([sampler]),
-            top_k_static=_static_top_k([sampler]),
-            greedy_only=sampler.greedy,
-        )[0])
-        self.n_dispatches += 2           # prefill + first-token sample
-        self.n_host_syncs += 1           # int(first)
-        self.n_admissions += 1
+            with TraceAnnotation("serve.admit.prefill"):
+                logits, self.cache = prefill_into_slot(
+                    self.cfg, self.params, prompt, self.context, self.cache,
+                    i, encoder_frames=encoder_frames,
+                    kv_dtype=self.cache_dtype,
+                )
+                key, sub = jax.random.split(jax.random.PRNGKey(seed))
+        with TraceAnnotation("serve.admit.sample"):
+            first = int(_admit_sample(
+                logits, sub[None], SlotSamplers.stack([sampler]),
+                spec_k=self.spec_k, rounds=self.rounds,
+                backend=self.backend, enable=_enable_bits([sampler]),
+                top_k_static=_static_top_k([sampler]),
+                greedy_only=sampler.greedy,
+            )[0])
+        with TraceAnnotation("serve.admit.book"):
+            self.n_dispatches += 2       # prefill + first-token sample
+            self.n_host_syncs += 1       # int(first)
+            self.n_admissions += 1
 
-        self.token = self.token.at[i].set(first)
-        self.pos = self.pos.at[i].set(prompt.shape[1])
-        self.keys = self.keys.at[i].set(key)
-        info = _SlotInfo(
-            rid, n_new - 1, [first], sampler,
-            context=[int(t) for t in np.asarray(prompt[0])] + [first],
-            eos_id=eos_id,
-        )
-        if info.remaining <= 0 or (eos_id is not None and first == eos_id):
-            self._finished.append(FinishedRequest(rid, info.tokens))
-            if self.paged:               # done at admission: pages go back
-                self.alloc.release(chain)
-        else:
-            self.slots[i] = info
-            self._step_args = None       # occupancy changed
-            if self.paged:
-                self._chains[i] = chain
-                row = np.zeros((self.max_chain,), np.int32)
-                row[:len(chain)] = chain
-                self.table = self.table.at[i].set(jnp.asarray(row))
+            self.token = self.token.at[i].set(first)
+            self.pos = self.pos.at[i].set(prompt.shape[1])
+            self.keys = self.keys.at[i].set(key)
+            info = _SlotInfo(
+                rid, n_new - 1, [first], sampler,
+                context=[int(t) for t in np.asarray(prompt[0])] + [first],
+                eos_id=eos_id,
+            )
+            if info.remaining <= 0 or (eos_id is not None
+                                       and first == eos_id):
+                self._finished.append(FinishedRequest(rid, info.tokens))
+                if self.paged:           # done at admission: pages go back
+                    self.alloc.release(chain)
+            else:
+                self.slots[i] = info
+                self._step_args = None   # occupancy changed
+                if self.paged:
+                    self._chains[i] = chain
+                    row = np.zeros((self.max_chain,), np.int32)
+                    row[:len(chain)] = chain
+                    self.table = self.table.at[i].set(jnp.asarray(row))
         return True
 
     # -- the compiled decode step -------------------------------------------
@@ -911,9 +937,10 @@ class ContinuousScheduler:
         ever run between calls — fusing K steps moves the host/device
         boundary, never the scheduling semantics.
         """
-        if self.step_horizon == 1:
-            return self._step_serial()
-        return self._step_fused()
+        with TraceAnnotation("serve.step", live=self.n_active):
+            if self.step_horizon == 1:
+                return self._step_serial()
+            return self._step_fused()
 
     def _step_serial(self) -> dict[Any, list[int]]:
         """One decode step over every active slot: {rid: tokens emitted}.
@@ -933,56 +960,58 @@ class ContinuousScheduler:
         if not live:
             return {}
         L = self.draft_len
-        slots_arr, active, enable, top_k_static, greedy_only = (
-            self._ensure_step_args(live))
+        with TraceAnnotation("serve.step.prepare"):
+            slots_arr, active, enable, top_k_static, greedy_only = (
+                self._ensure_step_args(live))
+            if L > 1:                    # host-side draft between steps
+                draft_host = np.zeros((self.n_slots, L - 1), np.int32)
+                for i, info in enumerate(self.slots):
+                    if info is not None:
+                        draft_host[i] = self.drafter(info.context, L - 1)
+                draft = jnp.asarray(draft_host)
+            else:
+                draft = jnp.zeros((self.n_slots, 0), jnp.int32)
 
-        n_live = len(live)
-        if L > 1:                        # host-side draft between steps
-            draft_host = np.zeros((self.n_slots, L - 1), np.int32)
-            for i, info in enumerate(self.slots):
-                if info is not None:
-                    draft_host[i] = self.drafter(info.context, L - 1)
-            draft = jnp.asarray(draft_host)
-        else:
-            draft = jnp.zeros((self.n_slots, 0), jnp.int32)
-
-        if self.paged:
-            (self.token, self.pos, self.keys, self.pool, out,
-             n_acc) = _scheduler_step_paged(
-                self.params, self.token, self.pos, self.keys, active,
-                self.pool, self.table, slots_arr, draft,
-                cfg=self.cfg, context=self.context, spec_k=self.spec_k,
-                rounds=self.rounds, backend=self.backend, enable=enable,
-                top_k_static=top_k_static, policy=self._policy,
-                draft_len=L, greedy_only=greedy_only,
-                page_impl=self.page_impl,
-            )
-        else:
-            (self.token, self.pos, self.keys, self.cache, out,
-             n_acc) = _scheduler_step(
-                self.params, self.token, self.pos, self.keys, active,
-                self.cache, slots_arr, draft,
-                cfg=self.cfg, spec_k=self.spec_k, rounds=self.rounds,
-                backend=self.backend, enable=enable,
-                top_k_static=top_k_static, policy=self._policy,
-                draft_len=L, greedy_only=greedy_only,
-            )
+        with TraceAnnotation("serve.step.launch"):
+            if self.paged:
+                (self.token, self.pos, self.keys, self.pool, out,
+                 n_acc) = _scheduler_step_paged(
+                    self.params, self.token, self.pos, self.keys, active,
+                    self.pool, self.table, slots_arr, draft,
+                    cfg=self.cfg, context=self.context, spec_k=self.spec_k,
+                    rounds=self.rounds, backend=self.backend, enable=enable,
+                    top_k_static=top_k_static, policy=self._policy,
+                    draft_len=L, greedy_only=greedy_only,
+                    page_impl=self.page_impl,
+                )
+            else:
+                (self.token, self.pos, self.keys, self.cache, out,
+                 n_acc) = _scheduler_step(
+                    self.params, self.token, self.pos, self.keys, active,
+                    self.cache, slots_arr, draft,
+                    cfg=self.cfg, spec_k=self.spec_k, rounds=self.rounds,
+                    backend=self.backend, enable=enable,
+                    top_k_static=top_k_static, policy=self._policy,
+                    draft_len=L, greedy_only=greedy_only,
+                )
         self.n_decode_steps += 1
         self.n_dispatches += 1
         self.n_host_syncs += 1
-        self.n_drafted += (L - 1) * n_live
+        self.n_drafted += (L - 1) * len(live)
 
+        with TraceAnnotation("serve.step.readback"):
+            out_host = np.asarray(out)
+            acc_host = np.asarray(n_acc)
         emitted: dict[Any, list[int]] = {}
-        out_host = np.asarray(out)
-        acc_host = np.asarray(n_acc)
-        for i, info in enumerate(self.slots):
-            if info is None:
-                continue
-            self.n_accepted += int(acc_host[i])
-            run = [int(t) for t in out_host[i, : int(acc_host[i]) + 1]]
-            run, done = self._finish_run(info, run)
-            self._commit_run(i, info, run, done, emitted)
-        self._maybe_retune_draft_len()
+        with TraceAnnotation("serve.step.commit"):
+            for i, info in enumerate(self.slots):
+                if info is None:
+                    continue
+                self.n_accepted += int(acc_host[i])
+                run = [int(t) for t in out_host[i, : int(acc_host[i]) + 1]]
+                run, done = self._finish_run(info, run)
+                self._commit_run(i, info, run, done, emitted)
+            self._maybe_retune_draft_len()
         return emitted
 
     def _step_fused(self) -> dict[Any, list[int]]:
@@ -1001,65 +1030,69 @@ class ContinuousScheduler:
             return {}
         K = self.step_horizon
         L = self.draft_len
-        slots_arr, active, enable, top_k_static, greedy_only = (
-            self._ensure_step_args(live))
-        remaining = jnp.asarray(
-            [s.remaining if s is not None else 0 for s in self.slots],
-            jnp.int32)
-        eos = jnp.asarray(
-            [-1 if s is None or s.eos_id is None else s.eos_id
-             for s in self.slots], jnp.int32)
+        with TraceAnnotation("serve.step.prepare"):
+            slots_arr, active, enable, top_k_static, greedy_only = (
+                self._ensure_step_args(live))
+            remaining = jnp.asarray(
+                [s.remaining if s is not None else 0 for s in self.slots],
+                jnp.int32)
+            eos = jnp.asarray(
+                [-1 if s is None or s.eos_id is None else s.eos_id
+                 for s in self.slots], jnp.int32)
 
-        if self.paged:
-            (self.token, self.pos, self.keys, self.pool, outs, accs,
-             acts) = _scheduler_horizon_paged(
-                self.params, self.token, self.pos, self.keys, active,
-                remaining, eos, self.pool, self.table, slots_arr,
-                cfg=self.cfg, context=self.context, spec_k=self.spec_k,
-                rounds=self.rounds, backend=self.backend, enable=enable,
-                top_k_static=top_k_static, policy=self._policy,
-                draft_len=L, greedy_only=greedy_only,
-                page_impl=self.page_impl, horizon=K,
-            )
-        else:
-            (self.token, self.pos, self.keys, self.cache, outs, accs,
-             acts) = _scheduler_horizon(
-                self.params, self.token, self.pos, self.keys, active,
-                remaining, eos, self.cache, slots_arr,
-                cfg=self.cfg, spec_k=self.spec_k, rounds=self.rounds,
-                backend=self.backend, enable=enable,
-                top_k_static=top_k_static, policy=self._policy,
-                draft_len=L, greedy_only=greedy_only, horizon=K,
-            )
+        with TraceAnnotation("serve.step.launch"):
+            if self.paged:
+                (self.token, self.pos, self.keys, self.pool, outs, accs,
+                 acts) = _scheduler_horizon_paged(
+                    self.params, self.token, self.pos, self.keys, active,
+                    remaining, eos, self.pool, self.table, slots_arr,
+                    cfg=self.cfg, context=self.context, spec_k=self.spec_k,
+                    rounds=self.rounds, backend=self.backend, enable=enable,
+                    top_k_static=top_k_static, policy=self._policy,
+                    draft_len=L, greedy_only=greedy_only,
+                    page_impl=self.page_impl, horizon=K,
+                )
+            else:
+                (self.token, self.pos, self.keys, self.cache, outs, accs,
+                 acts) = _scheduler_horizon(
+                    self.params, self.token, self.pos, self.keys, active,
+                    remaining, eos, self.cache, slots_arr,
+                    cfg=self.cfg, spec_k=self.spec_k, rounds=self.rounds,
+                    backend=self.backend, enable=enable,
+                    top_k_static=top_k_static, policy=self._policy,
+                    draft_len=L, greedy_only=greedy_only, horizon=K,
+                )
         self.n_decode_steps += K
         self.n_dispatches += 1           # the whole horizon is one launch
         self.n_host_syncs += 1           # ... and one boundary readback
         self.n_horizons += 1
 
-        outs_host = np.asarray(outs)     # (K, B, L)
-        accs_host = np.asarray(accs)     # (K, B)
-        acts_host = np.asarray(acts)     # (K, B) entry mask per iteration
-        self.n_wasted_steps += int((~acts_host.any(axis=1)).sum())
-
+        with TraceAnnotation("serve.step.readback"):
+            outs_host = np.asarray(outs)     # (K, B, L)
+            accs_host = np.asarray(accs)     # (K, B)
+            acts_host = np.asarray(acts)     # (K, B) entry mask per step
         emitted: dict[Any, list[int]] = {}
-        for j in range(K):
-            n_live_j = int(acts_host[j].sum())
-            self.n_drafted += (L - 1) * n_live_j
-            for i, info in enumerate(self.slots):
-                if bool(acts_host[j, i]) != (info is not None):
-                    raise RuntimeError(
-                        "fused horizon freeze mask diverged from the host "
-                        f"slot table at iteration {j}, slot {i} — device "
-                        "done-detection and host truncation disagree"
-                    )
-                if info is None:
-                    continue
-                self.n_accepted += int(accs_host[j, i])
-                run = [int(t)
-                       for t in outs_host[j, i, : int(accs_host[j, i]) + 1]]
-                run, done = self._finish_run(info, run)
-                self._commit_run(i, info, run, done, emitted)
-        self._maybe_retune_draft_len()
+        with TraceAnnotation("serve.step.commit"):
+            self.n_wasted_steps += int((~acts_host.any(axis=1)).sum())
+            for j in range(K):
+                n_live_j = int(acts_host[j].sum())
+                self.n_drafted += (L - 1) * n_live_j
+                for i, info in enumerate(self.slots):
+                    if bool(acts_host[j, i]) != (info is not None):
+                        raise RuntimeError(
+                            "fused horizon freeze mask diverged from the "
+                            f"host slot table at iteration {j}, slot {i} — "
+                            "device done-detection and host truncation "
+                            "disagree"
+                        )
+                    if info is None:
+                        continue
+                    self.n_accepted += int(accs_host[j, i])
+                    run = [int(t) for t in
+                           outs_host[j, i, : int(accs_host[j, i]) + 1]]
+                    run, done = self._finish_run(info, run)
+                    self._commit_run(i, info, run, done, emitted)
+            self._maybe_retune_draft_len()
         return emitted
 
     # -- live re-tuning -----------------------------------------------------

@@ -10,6 +10,9 @@ The loop is deliberately synchronous and single-threaded: one ``step()``
 is one batched decode launch, and admission happens between steps.  The
 async transports a production deployment needs (HTTP, streaming) bolt onto
 ``submit``/``step``/``drain`` without touching the device code.
+
+Admitting from the queue and collecting completions are ``jax.profiler``
+host spans (``serve.queue``, ``serve.drain``) beside the scheduler's own.
 """
 from __future__ import annotations
 
@@ -19,6 +22,7 @@ from collections import deque
 from typing import Any, Sequence
 
 import jax
+from jax.profiler import TraceAnnotation
 
 from repro.models.config import ModelConfig
 from repro.serving.sampler import SamplerConfig
@@ -29,9 +33,9 @@ from repro.serving.scheduler import ContinuousScheduler
 class Request:
     """One generation request.
 
-    ``arrival`` is the decode-step index at which the request becomes
-    visible to the server (0 = available immediately) — the simulated
-    staggered-arrival knob used by the tests and the benchmark.
+    ``arrival`` is the decode-step index at which ``run`` makes the
+    request visible to the server (0 = available immediately): the
+    staggered-arrival knob of scripted workloads.  ``submit`` ignores it.
     """
 
     rid: Any
@@ -46,6 +50,11 @@ class Request:
 
 @dataclasses.dataclass
 class Completion:
+    """A finished request.  ``arrival_time`` (at ``submit``) and
+    ``finish_time`` (when its completion was collected) are
+    ``time.perf_counter()`` readings: only their difference means
+    anything."""
+
     rid: Any
     tokens: list[int]
     arrival_step: int
@@ -111,7 +120,7 @@ class RunaheadServer:
         self.scheduler.validate_request(req.n_new, req.sampler,
                                         prompt_len=len(req.prompt))
         self._pending.append(req)
-        self._meta[req.rid] = (self._step_idx, -1, time.time())
+        self._meta[req.rid] = (self._step_idx, -1, time.perf_counter())
 
     def step(self) -> list[Completion]:
         """Admit what fits, advance one scheduler boundary, return new
@@ -158,28 +167,30 @@ class RunaheadServer:
     # -- internals ----------------------------------------------------------
 
     def _admit_pending(self) -> None:
-        while self._pending and self.scheduler.has_free_slot():
-            req = self._pending[0]
-            if not self.scheduler.admit(
-                req.rid, req.prompt, req.n_new, req.seed, req.sampler,
-                eos_id=req.eos_id,
-            ):
-                break                        # pool filled under us
-            self._pending.popleft()
-            arr, _, t0 = self._meta[req.rid]
-            self._meta[req.rid] = (arr, self._step_idx, t0)
+        with TraceAnnotation("serve.queue"):
+            while self._pending and self.scheduler.has_free_slot():
+                req = self._pending[0]
+                if not self.scheduler.admit(
+                    req.rid, req.prompt, req.n_new, req.seed, req.sampler,
+                    eos_id=req.eos_id,
+                ):
+                    break                    # pool filled under us
+                self._pending.popleft()
+                arr, _, t0 = self._meta[req.rid]
+                self._meta[req.rid] = (arr, self._step_idx, t0)
 
     def _drain_finished(self) -> list[Completion]:
-        out = []
-        now = time.time()
-        for fin in self.scheduler.pop_finished():
-            arr, adm, t0 = self._meta.pop(fin.rid)
-            out.append(Completion(
-                rid=fin.rid, tokens=fin.tokens, arrival_step=arr,
-                admit_step=adm, finish_step=self._step_idx,
-                arrival_time=t0, finish_time=now,
-            ))
-        return out
+        with TraceAnnotation("serve.drain"):
+            out = []
+            now = time.perf_counter()
+            for fin in self.scheduler.pop_finished():
+                arr, adm, t0 = self._meta.pop(fin.rid)
+                out.append(Completion(
+                    rid=fin.rid, tokens=fin.tokens, arrival_step=arr,
+                    admit_step=adm, finish_step=self._step_idx,
+                    arrival_time=t0, finish_time=now,
+                ))
+            return out
 
 
 def generate_oneshot_reference(
