@@ -392,6 +392,7 @@ def decode_attend(
     cache: KVCache,
     *,
     window: int | jax.Array = 0,
+    write_mask: jax.Array | None = None,
 ) -> tuple[jax.Array, KVCache]:
     """One decode step: append K/V at pos (mod capacity), attend over cache.
 
@@ -400,6 +401,15 @@ def decode_attend(
     scalar path keeps the contiguous ``dynamic_update_slice`` write; the
     vector path scatters one ring slot per row and builds a per-row
     validity mask — same values row-for-row when the positions coincide.
+
+    ``write_mask`` (B,) bool, per-slot positions only: a row whose mask is
+    False aims its write past the ring and the scatter drops it, so that
+    row's K/V (and int8 scales) come back bit-equal to ``cache``.  This is
+    where the serving step freezes an inactive lane's K/V: one dropped row
+    instead of a select over the whole cache
+    (``models.decode.freeze_cache_lanes``).  The row still attends — over
+    its cache without the new row — and its output is the caller's to
+    discard.
     """
     B = x.shape[0]
     q = _project_q(p, cfg, x)                                # (B,1,nq,hd)
@@ -416,10 +426,13 @@ def decode_attend(
 
     if per_slot:
         rows = jnp.arange(B)
+        at = slot if write_mask is None else jnp.where(write_mask, slot, C)
 
         def write(buf, new):                     # (B,C,...) <- (B,1,...)
-            return buf.at[rows, slot].set(new[:, 0])
+            return buf.at[rows, at].set(new[:, 0], mode="drop")
     else:
+        if write_mask is not None:
+            raise ValueError("write_mask needs per-slot (B,) positions")
 
         def write(buf, new):
             start = (0, slot) + (0,) * (buf.ndim - 2)

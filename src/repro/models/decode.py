@@ -255,12 +255,13 @@ def _prefill_run(kind, cfg, run_params, x, positions, context, *,
 # decode step
 # ---------------------------------------------------------------------------
 
-def _step_block(kind, cfg, p, x, pos, entry, *, capacity_mode):
+def _step_block(kind, cfg, p, x, pos, entry, *, capacity_mode, write_mask):
     """One block for one token.  x: (B, 1, D)."""
     eps = cfg.norm_eps
     if kind in ("dense", "moe"):
         h = apply_norm(cfg.norm, p["ln1"], x, eps)
-        a, kv = attn_lib.decode_attend(p["attn"], cfg, h, pos, entry["kv"])
+        a, kv = attn_lib.decode_attend(p["attn"], cfg, h, pos, entry["kv"],
+                                       write_mask=write_mask)
         x = x + a
         h = apply_norm(cfg.norm, p["ln2"], x, eps)
         if kind == "dense":
@@ -274,7 +275,7 @@ def _step_block(kind, cfg, p, x, pos, entry, *, capacity_mode):
         w = 0 if kind == "hymba_global" else cfg.sliding_window
         h = apply_norm(cfg.norm, p["ln1"], x, eps)
         a, kv = attn_lib.decode_attend(p["attn"], cfg, h, pos, entry["kv"],
-                                       window=w)
+                                       window=w, write_mask=write_mask)
         s, ssm_state = ssm_lib.ssm_step(p["ssm"], cfg, h, entry["ssm"])
         a = apply_norm(cfg.norm, p["attn_norm"], a, eps)
         s = apply_norm(cfg.norm, p["ssm_norm"], s, eps)
@@ -292,7 +293,8 @@ def _step_block(kind, cfg, p, x, pos, entry, *, capacity_mode):
         return x + out, {"state": state}
     if kind == "whisper_dec":
         h = apply_norm(cfg.norm, p["ln1"], x, eps)
-        a, kv = attn_lib.decode_attend(p["attn"], cfg, h, pos, entry["kv"])
+        a, kv = attn_lib.decode_attend(p["attn"], cfg, h, pos, entry["kv"],
+                                       write_mask=write_mask)
         x = x + a
         h = apply_norm(cfg.norm, p["ln2"], x, eps)
         x = x + attn_lib.decode_cross_attend(
@@ -313,6 +315,7 @@ def decode_step(
     *,
     compute_dtype=jnp.bfloat16,
     capacity_mode: str = "fifo",
+    write_mask: jax.Array | None = None,
 ) -> tuple[jax.Array, Cache]:
     """One decode step: returns (logits (B, V) f32, updated cache).
 
@@ -321,6 +324,11 @@ def decode_step(
     in-flight requests, one position per slot).  Either way this is ONE
     compiled function: the continuous scheduler re-uses the same jitted
     step across arbitrary slot occupancy.
+
+    ``write_mask`` (B,) bool, with per-slot ``pos``: rows where it is
+    False write no K/V row (``attention.decode_attend``), so their ring
+    K/V comes back bit-equal to ``cache``.  Recurrent state is rewritten
+    for every row regardless; ``freeze_cache_lanes`` restores it.
     """
     B = token.shape[0]
     pos = jnp.asarray(pos, jnp.int32)
@@ -334,14 +342,25 @@ def decode_step(
     for run_params, entry, (kind, _) in zip(
         params["runs"], cache, layer_plan(cfg)
     ):
-        def body(x, inp):
-            p_l, entry_l = inp
-            x, new_entry = _step_block(
-                kind, cfg, p_l, x, pos, entry_l, capacity_mode=capacity_mode
+        # The cache rides in the carry and each layer is updated where it
+        # lies.  Scanned as xs -> ys, the step would emit a second stacked
+        # cache, which XLA then copies into the donated one.
+        def body(carry, p_l):
+            x, entry, i = carry
+            entry_l = jax.tree_util.tree_map(
+                lambda a: jax.lax.dynamic_index_in_dim(a, i, keepdims=False),
+                entry)
+            x, new_l = _step_block(
+                kind, cfg, p_l, x, pos, entry_l, capacity_mode=capacity_mode,
+                write_mask=write_mask,
             )
-            return x, new_entry
+            entry = jax.tree_util.tree_map(
+                lambda a, n: jax.lax.dynamic_update_index_in_dim(a, n, i, 0),
+                entry, new_l)
+            return (x, entry, i + 1), None
 
-        x, new_entry = jax.lax.scan(body, x, (run_params, entry))
+        (x, new_entry, _), _ = jax.lax.scan(
+            body, (x, entry, jnp.int32(0)), run_params)
         new_cache.append(new_entry)
 
     x = apply_norm(cfg.norm, params["final_norm"], x, cfg.norm_eps)
@@ -510,23 +529,39 @@ def mask_table_rows(table: jax.Array, active: jax.Array) -> jax.Array:
     return jnp.where(active[:, None], table, 0)
 
 
+# Cache entries a masked ``decode_step`` already leaves bit-frozen in
+# inactive lanes: ring K/V (the dropped row write) and the encoder K/V,
+# which decode only reads.
+_FROZEN_BY_STEP = frozenset({"kv", "enc_k", "enc_v"})
+
+
 def freeze_cache_lanes(new_cache, old_cache, active: jax.Array):
     """Bit-freeze inactive batch lanes: keep ``old_cache`` where ``~active``.
 
-    The dense dual of ``mask_table_rows``: a dense ring cache has no null
-    page to absorb a dead lane's writes, so the serving step instead
-    selects the pre-step state back in for every inactive lane.  This is
-    what lets a fused horizon (serving/scheduler.py) leave a slot that
-    finished at iteration j < K bit-identical to the state per-step
-    serving would have evicted — including recurrent (SSM/xLSTM) state,
-    which would otherwise drift under dead steps.  Cache leaves are
-    layer-stacked with the batch on axis 1.
+    The dense dual of ``mask_table_rows``, for a ``new_cache`` made by
+    ``decode_step(..., write_mask=active)``.  A dense ring cache has no
+    null page to absorb a dead lane's write, so that step drops the one
+    K/V row an inactive lane would write: its ``"kv"`` entries come back
+    frozen and pass through here untouched, as do the read-only
+    ``"enc_k"``/``"enc_v"``.  What is left to select is recurrent state
+    (SSM ``"ssm"``, xLSTM ``"state"``): its step rewrites the whole lane,
+    so the pre-step state is selected back in for every inactive lane —
+    a select over O(state), not over the K/V cache.  This is what lets a
+    fused horizon (serving/scheduler.py) leave a slot that finished at
+    iteration j < K bit-identical to the state per-step serving would
+    have evicted.  Cache leaves are layer-stacked with the batch on axis
+    1; each entry is judged by its own keys.
     """
     def sel(new, old):
         mask = active.reshape((1, -1) + (1,) * (new.ndim - 2))
         return jnp.where(mask, new, old)
 
-    return jax.tree_util.tree_map(sel, new_cache, old_cache)
+    return [
+        {name: leaf if name in _FROZEN_BY_STEP
+         else jax.tree_util.tree_map(sel, leaf, old[name])
+         for name, leaf in new.items()}
+        for new, old in zip(new_cache, old_cache)
+    ]
 
 
 def paged_prefill(

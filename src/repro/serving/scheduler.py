@@ -233,10 +233,12 @@ def _step_body(params, token, pos, keys, active, cache, slots, draft,
     there is literally one body to compile.
     """
     if draft_len == 1:
-        logits, stepped = decode_step(cfg, params, token, pos, cache)
+        logits, stepped = decode_step(cfg, params, token, pos, cache,
+                                      write_mask=active)
         # inactive lanes keep their pre-step cache state — the serial
         # analogue of the verify branch's n_keep=0 rollback, and what
-        # keeps a slot that finishes mid-horizon bit-frozen
+        # keeps a slot that finishes mid-horizon bit-frozen: the masked
+        # step froze their K/V, this restores their recurrent state
         new_cache = freeze_cache_lanes(stepped, cache, active)
         ks = jax.vmap(jax.random.split)(keys)               # (B, 2, 2)
         new_keys = jnp.where(active[:, None], ks[:, 0], keys)

@@ -146,3 +146,39 @@ def test_qwen3_4b_decode_step_fits_one_v5e(chip):
                           _shape(chip, (B,), jnp.int32), cache).compile()
     ma = compiled.memory_analysis()
     assert ma.argument_size_in_bytes + ma.temp_size_in_bytes <= HBM_BYTES
+
+
+def test_dense_serving_step_updates_cache_in_place_on_v5e(chip):
+    """The serving step at full-width internlm2-1.8b, 32 slots x 1,024,
+    cache donated: the K/V cache (3.22 GB) is updated where it lies, so
+    the step's temp holds at most a layer's slice of it, not a second
+    cache (a freeze select or a copy of the layer scan's output)."""
+    from repro.configs.registry import get_config
+    from repro.models.decode import init_cache
+    from repro.models.transformer import init_params
+    from repro.serving.sampler import SamplerConfig, SlotSamplers
+    from repro.serving.scheduler import _scheduler_step
+
+    cfg = get_config("internlm2-1.8b")
+    B, context = 32, 1024
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: _shape(chip, a.shape, a.dtype), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda k: init_params(cfg, k, jnp.bfloat16), jax.random.PRNGKey(0)))
+    cache = on_chip(jax.eval_shape(
+        lambda: init_cache(cfg, B, context, jnp.bfloat16)))
+    slots = on_chip(jax.eval_shape(
+        lambda: SlotSamplers.stack([SamplerConfig(greedy=True)] * B)))
+    compiled = _scheduler_step.lower(
+        params, _shape(chip, (B,), jnp.int32), _shape(chip, (B,), jnp.int32),
+        _shape(chip, (B, 2), jnp.uint32), _shape(chip, (B,), jnp.bool_),
+        cache, slots, _shape(chip, (B, 0), jnp.int32), cfg=cfg, spec_k=5,
+        rounds=8, backend="pallas", enable=(False, False, False),
+        top_k_static=None, greedy_only=True).compile()
+    cache_bytes = sum(a.size * a.dtype.itemsize
+                      for a in jax.tree_util.tree_leaves(cache))
+    layer_bytes = cache_bytes // cfg.n_layers
+    assert compiled.memory_analysis().temp_size_in_bytes <= layer_bytes
