@@ -1,7 +1,8 @@
 """Decides ``correct``: the served tokens against the plain reference.
 
 A sample of the finished requests, drawn from the seed with the longest
-of each kind in it, is run through the float32 reference (``model.py``)
+of each kind in it, is run through the float32 reference (the reference
+module of the configuration, ``spec.py``)
 once over its prompt and served tokens.  Two numbers are compared, each
 against the limit the configuration file states, and each in standard
 deviations of the reference's logits at the position (so that a limit
@@ -30,7 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from model import Dims, forward
+from spec import reference_of
 
 # Served tokens of each kind (greedy, sampled) the check reads after the
 # window: some hundreds, as few as keep the reference shorter than it.
@@ -61,13 +62,14 @@ def sample(records: list, seed: int) -> list:
 
 
 @functools.partial(jax.jit, static_argnames=("dm", "top_k", "quant"))
-def _gaps(w, seq, at, served, temperature, top_p, key, *, dm: Dims,
-          top_k: int, quant: str | None):
+def _gaps(w, seq, at, served, temperature, top_p, key, *, dm, top_k: int,
+          quant: str | None):
     """Per position ``at`` (the logits that chose ``served``): the
     reference's best logit, its logit of the served token and its
     smallest kept logit.  With ``quant``, also the reference logit of the
     token the quantised model puts first (greedy) and of one it samples
     from its own kept set."""
+    forward = reference_of(dm).forward
     ref = forward(w, seq, dm=dm)[at]                        # (N, V)
     best = ref.max(-1)
     tok = jnp.take_along_axis(ref, served[:, None], -1)[:, 0]
@@ -98,7 +100,7 @@ def _kept_floor(logits, temperature, top_p, top_k):
     return jnp.take_along_axis(vals, (n - 1)[:, None], -1)[:, 0] * temperature
 
 
-def readings(w, dm: Dims, records: list, sampler: dict, context: int,
+def readings(w, dm, records: list, sampler: dict, context: int,
              seed: int, quant: str | None = None) -> dict:
     """The compared numbers over ``records`` (each with its served
     tokens).  With ``quant``, the control's numbers beside them."""

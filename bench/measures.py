@@ -8,8 +8,6 @@ import importlib.util
 import math
 import os
 
-from model import Dims
-
 METRICS_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                            "metrics")
 
@@ -19,7 +17,7 @@ class Run:
     cell: str
     seconds: float
     setup_s: float
-    dims: Dims
+    dims: object             # the Dims of the cell's reference module
     serve: dict
     traffic: dict
     records: list            # serve.Record
@@ -133,7 +131,7 @@ def dump(run: Run, trace_host) -> dict:
     }
 
 
-def from_dump(d: dict, dims: Dims, serve: dict, traffic: dict,
+def from_dump(d: dict, dims, serve: dict, traffic: dict,
               peaks: dict | None) -> Run:
     """The ``Run`` that ``dump`` wrote (prompts as zeros: only their
     lengths are kept)."""

@@ -75,24 +75,17 @@ def fail(msg: str, code: int = 2) -> int:
 
 
 def program_config(dims, arch: str, reduced: bool):
-    """The program's ModelConfig for ``arch``, with the norm epsilon the
-    configuration file states; raises where it departs from the file."""
+    """The program's ModelConfig for ``arch``, with the fields the
+    configuration file sets on it (its reference module's ``set_fields``);
+    raises where it departs from the file (``program_fields``)."""
     import dataclasses
 
     from repro.configs.registry import get_config
 
-    cfg = dataclasses.replace(get_config(arch), norm_eps=dims.eps)
-    want = dict(n_layers=dims.layers, d_model=dims.d, n_heads=dims.heads,
-                n_kv_heads=dims.kv_heads, head_dim=dims.head_dim,
-                d_ff=dims.ff, vocab=dims.vocab, rope_theta=dims.rope_theta,
-                norm_eps=dims.eps, tie_embeddings=dims.tied,
-                qk_norm=dims.qk_norm, qkv_bias=False, act="swiglu",
-                norm="rmsnorm", family="dense", learned_pos=False)
-    if reduced:
-        cfg = cfg.scaled(n_layers=dims.layers, d_model=dims.d,
-                         n_heads=dims.heads, n_kv_heads=dims.kv_heads,
-                         d_head=dims.head_dim, d_ff=dims.ff,
-                         vocab=dims.vocab)
+    ref = spec.reference_of(dims)
+    cfg = dataclasses.replace(get_config(arch),
+                              **ref.set_fields(dims, reduced))
+    want = ref.program_fields(dims)
     diff = {k: (getattr(cfg, k), v) for k, v in want.items()
             if getattr(cfg, k) != v}
     if diff:
@@ -117,7 +110,7 @@ def check_layout(weights, cfg) -> None:
     if (tu.tree_structure(want) != tu.tree_structure(got)
             or tu.tree_leaves(want) != tu.tree_leaves(got)):
         raise spec.SpecError("the program's parameter tree differs from the "
-                             "layout model.py makes")
+                             "layout the reference module makes")
 
 
 def seed_key(seed: int):
@@ -135,18 +128,18 @@ class Setup:
         import jax
 
         import serve
-        from model import Dims, make_weights
         from traffic import lengths_used
 
         self.cell = cell
         conf = cell.config
+        ref = spec.reference(conf)
         small = conf["cpu_rehearsal"] if reduced else {}
-        self.dims = Dims.of(conf["model"], {k: v for k, v in small.items()
-                                            if k in conf["model"]})
+        self.dims = ref.Dims.of(conf["model"], {k: v for k, v in small.items()
+                                                if k in conf["model"]})
         self.serve = dict(conf["serve"], **{k: v for k, v in small.items()
                                             if k in conf["serve"]})
         self.cfg = program_config(self.dims, conf["arch"], reduced)
-        self.weights = make_weights(self.dims, seed_key(seed))
+        self.weights = ref.make_weights(self.dims, seed_key(seed))
         jax.block_until_ready(self.weights)
         check_layout(self.weights, self.cfg)
         self.lengths = lengths_used(cell.traffic)

@@ -1,11 +1,47 @@
 """What one cell is: its entry in BENCHMARK.json, its configuration file,
-its traffic file and the table of peaks.  Everything is found by name, so
-a later cell, configuration or metric is a new file and a new entry."""
+its traffic file, the reference module its configuration names, and the
+table of peaks.  Everything is found by name, so a later cell,
+configuration or metric is a new file and a new entry.
+
+A configuration file may name its reference module, ``"reference":
+"<name>"``: ``bench/<name>.py``, dots for subdirectories.  With no such
+key it is ``model``, the dense stack.  The harness reaches the model only
+through that module (``reference``), or through the module that made the
+``Dims`` it holds (``reference_of``), and never names one.  A reference
+module provides:
+
+  Dims.of(model, override)   the sizes from the file's ``model`` block
+                             (``override``: the ``--reduced`` ones);
+                             frozen and hashable, with at least
+                             ``layers``, ``d``, ``vocab``, ``vocab_rows``
+  make_weights(dm, key)      every weight, in the program's parameter
+                             tree, in one jitted call
+  forward(w, tokens, *, dm, quant=None)
+                             float32 logits of every position;
+                             ``quant="fp8"`` is the control
+  program_fields(dm)         the program's ``ModelConfig`` fields the file
+                             fixes (checked before serving)
+  set_fields(dm, reduced)    the fields set on the program's config: those
+                             the file states over it, and at ``--reduced``
+                             the rehearsal's sizes
+  param_count, weight_bytes, kv_bytes_per_position, decode_token_flops,
+  prefill_flops, decode_step_bytes
+                             the work counts ``work.py`` answers with
+
+So a new architecture joins the benchmark as new files: its configuration
+file, its reference module where no existing one covers its layers, a
+traffic file where no mix fits, and its entries in BENCHMARK.json.  The
+room is for the first stack that is not dense, DeepSeek-V2-Lite: latent
+attention, 64 routed experts (6 a token) with 2 shared, and one leading
+dense layer.
+"""
 from __future__ import annotations
 
 import dataclasses
+import importlib.util
 import json
 import os
+import sys
 
 BENCH_DIR = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(BENCH_DIR)
@@ -64,3 +100,27 @@ def peaks_for(device_kind: str) -> dict:
         raise SpecError(f"no peaks for device kind {device_kind!r} in "
                         f"peaks.json (have {sorted(table['devices'])})")
     return table["devices"][device_kind]
+
+
+def reference(config: dict):
+    """The reference module the configuration file names, imported once
+    under that name (``model`` where it names none)."""
+    name = config.get("reference", "model")
+    if name not in sys.modules:
+        path = os.path.join(BENCH_DIR, *name.split(".")) + ".py"
+        if not os.path.isfile(path):
+            raise SpecError(f"no reference module {name!r} ({path})")
+        loader = importlib.util.spec_from_file_location(name, path)
+        module = importlib.util.module_from_spec(loader)
+        sys.modules[name] = module
+        try:
+            loader.loader.exec_module(module)
+        except BaseException:
+            del sys.modules[name]
+            raise
+    return sys.modules[name]
+
+
+def reference_of(dims):
+    """The reference module that made ``dims``."""
+    return sys.modules[type(dims).__module__]

@@ -56,7 +56,6 @@ def recorded(tmp_path):
     import shutil
 
     import measures
-    from model import Dims
 
     path = tmp_path / "trace.xplane.pb"
     with gzip.open(RECORDED) as src, open(path, "wb") as dst:
@@ -64,7 +63,8 @@ def recorded(tmp_path):
     with gzip.open(RECORDED_RUN) as f:
         d = json.load(f)
     cell = spec.load_cell("qwen3-4b.chat")
-    run = measures.from_dump(d, Dims.of(cell.config["model"]),
+    dims = spec.reference(cell.config).Dims.of(cell.config["model"])
+    run = measures.from_dump(d, dims,
                              cell.config["serve"], cell.traffic,
                              spec.peaks_for("TPU v5 lite"))
     run.trace = devtrace.load(str(path))
@@ -92,3 +92,27 @@ def test_recorded_shares_lie_in_range(tmp_path, name):
 
     v = measures.load_reader(name)(recorded(tmp_path))
     assert v is not None and 0.0 < v < 100.0, v
+
+
+# Every metric's reading of the recorded run and trace, as the readers gave
+# it when the work counts sat in ``work.py``: reaching the model through
+# the reference module moves no reading.
+KEPT = {
+    "admit_ms_p50": 40.53574900000001,
+    "decode_hbm_share": 33.040867930909286,
+    "device_idle_share": 11.9869691931767,
+    "gap_p999_ms": 181.23586000000103,
+    "mfu": 4.297563772439391,
+    "setup_s": 72.243815369,
+    "solver_roofline": 0.7161113678289308,
+    "solver_share": 5.022703466048417,
+    "step_ms_p50": 35.82607299999552,
+    "tokens_per_s": 160.2,
+}
+
+
+def test_recorded_run_reads_unmoved(tmp_path):
+    import measures
+
+    run = recorded(tmp_path)
+    assert {n: measures.load_reader(n)(run) for n in KEPT} == KEPT
